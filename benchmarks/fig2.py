@@ -8,8 +8,9 @@ Protocol mirrors the paper §4: LLaVA-1.5-7B (frozen CLIP ViT-L/14 tower +
 projector + Vicuna-7B, stage-2 behaviour), ZeRO-2 (grads reduce-scattered,
 Adam states sharded over DP; params replicated), DP swept 1..8.  Ground
 truth is the compiled-XLA per-device peak (the quantity whose overflow is
-the OoM the paper prevents); each DP degree compiles in a subprocess with
-that many devices.
+the OoM the paper prevents); each DP degree compiles on XLA:CPU in a
+subprocess with that many forced host devices, so these are compiler
+numbers, never chip measurements.
 """
 
 from __future__ import annotations
@@ -89,6 +90,7 @@ def run_cell(dp: int, seq: int, mbs: int) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(
         os.path.join(os.path.dirname(__file__), "..", "src"))
+    env["JAX_PLATFORMS"] = "cpu"          # an XLA:CPU compile, never a chip
     code = _CELL_CODE.format(dp=dp, seq=seq, mbs=mbs)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=1800)

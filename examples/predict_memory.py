@@ -77,6 +77,7 @@ def main():
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..",
                                          "src")
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
+        env["JAX_PLATFORMS"] = "cpu"      # an XLA:CPU compile, never a chip
         code = f"""
 import jax
 from repro.launch.dryrun import lower_cell

@@ -27,7 +27,7 @@ work runs inside one jitted ``lax.scan`` over pipeline stages:
   donated carry of running ``(best, pool, draft, hit, offload)``
   buffers, reproducing the numpy loop's strictly-greater peak-stage
   provenance update; everything is int64 under
-  ``jax.experimental.enable_x64`` (jax's default int32 canonicalization
+  ``jax.enable_x64(True)`` (jax's default int32 canonicalization
   would overflow byte counts);
 * folded tables are cached on the engine keyed by everything that
   determines their values (arch, policy, meshes, knob axes, profile
@@ -322,7 +322,7 @@ def _group_tables(engine, grid, cols, cfg, model, rows, rules, rep_ctx,
 def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
     """Drop-in twin of :func:`repro.core.batch.sweep_columnar` running
     the per-cell composition under jax; byte-identical results."""
-    from jax.experimental import enable_x64
+    import jax
 
     t0 = time.perf_counter()
     grid.check_parallel()
@@ -419,7 +419,7 @@ def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
                 else np.zeros(0, I64)
             carry0 = tuple(np.zeros((n_lm, inner), I64)
                            for _ in range(6))
-            with enable_x64():
+            with jax.enable_x64(True):
                 best, bp, bd, bh, bo, bs = compose(
                     carry0, tabs, (c_aff, c_b, c_ctr, c_ho, t2),
                     has_profile=profile is not None,

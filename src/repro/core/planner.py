@@ -53,6 +53,30 @@ CHIPS: dict[str, ChipSpec] = {
     "h200": ChipSpec("h200", 141 * GiB, vendor="nvidia"),
 }
 
+#: ``jax.devices()[0].device_kind`` -> CHIPS key, so a launcher plans for
+#: the chip it actually runs on
+DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "NVIDIA A100-SXM4-40GB": "a100-40g",
+    "NVIDIA A100-SXM4-80GB": "a100-80g",
+    "NVIDIA H100 80GB HBM3": "h100",
+    "NVIDIA H200": "h200",
+}
+
+
+def chip_of_device_kind(kind: str) -> str:
+    """The CHIPS key of a device kind; an unknown kind is an error (no
+    default chip: planning a job against the wrong HBM is the failure
+    the guard exists to prevent)."""
+    if kind not in DEVICE_KINDS:
+        raise ValueError(f"unknown device kind {kind!r}; known: "
+                         f"{sorted(DEVICE_KINDS)}")
+    return DEVICE_KINDS[kind]
+
+
 V5E_HBM = CHIPS["v5e"].hbm_bytes      # backward-compat alias
 # XLA reserves working space; plan against a fraction of physical HBM.
 HEADROOM = 0.92

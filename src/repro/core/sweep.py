@@ -1371,6 +1371,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             return 2
         return 0
 
+    if args.engine == "jax":
+        from repro.launch import compile_cache
+        compile_cache.enable()
     res = sweep(grid, mode=args.mode, jobs=args.jobs, engine=args.engine)
     if len(res) == 0:
         print(_empty_grid_msg())

@@ -95,7 +95,7 @@ def _pallas_eval(n_events, n_pad, block, interpret):
 
 
 def segmented_cummax(deltas, backend: str = "jax", block: int = _BLOCK,
-                     interpret: bool = True) -> np.ndarray:
+                     interpret: bool = False) -> np.ndarray:
     """Drop-in twin of :func:`repro.core.batch.liveness_peak_batch`
     (``backend="numpy"`` delegates to the reference; ``"jax"`` and
     ``"pallas"`` produce byte-identical int64 peaks)."""
@@ -107,9 +107,8 @@ def segmented_cummax(deltas, backend: str = "jax", block: int = _BLOCK,
     n_events, n = deltas.shape
 
     import jax
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         if backend == "jax":
             out = _jax_eval()(deltas)
         else:
@@ -123,7 +122,7 @@ def segmented_cummax(deltas, backend: str = "jax", block: int = _BLOCK,
 
 
 @contextlib.contextmanager
-def use_backend(backend: str = "jax", interpret: bool = True):
+def use_backend(backend: str = "jax", interpret: bool = False):
     """Route ``core.batch.liveness_peak_batch`` through an accelerated
     backend for the dynamic extent of the context (``"numpy"`` is a
     no-op).  Used by tests to run real columnar liveness sweeps through

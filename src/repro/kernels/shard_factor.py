@@ -23,7 +23,7 @@ only ever blocks another x1 attempt, so including such steps is
 value-identical per element (the reference documents the same argument
 for all-ones *columns*; here it holds per cell).  Globally dead axes
 are still dropped host-side as a pure optimisation.  Everything is
-int64 + floor-division under ``jax.experimental.enable_x64`` — parity
+int64 + floor-division under ``jax.enable_x64(True)`` — parity
 with the reference is asserted step-for-step on randomized programs and
 on real sweeps in tests/test_shard_factor.py.
 
@@ -178,7 +178,7 @@ def _pallas_eval(steps, n_dims, n_axes, n_pad, block, interpret):
 
 def shard_factor(dims, axes, sizes: dict, rules: dict, extra=(),
                  backend: str = "jax", block: int = _BLOCK,
-                 interpret: bool = True) -> np.ndarray:
+                 interpret: bool = False) -> np.ndarray:
     """Drop-in twin of :func:`repro.core.batch.batch_shard_factor`.
 
     ``backend="numpy"`` delegates to the reference; ``"jax"`` and
@@ -205,9 +205,8 @@ def shard_factor(dims, axes, sizes: dict, rules: dict, extra=(),
                    for a in names])
 
     import jax
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         if backend == "jax":
             st = np.asarray(steps, np.int32)
             out = _jax_eval()(a2, s2, st[:, 0], st[:, 1], st[:, 2])
@@ -224,12 +223,11 @@ def shard_factor(dims, axes, sizes: dict, rules: dict, extra=(),
 
 
 @contextlib.contextmanager
-def use_backend(backend: str = "jax", interpret: bool = True):
+def use_backend(backend: str = "jax", interpret: bool = False):
     """Route ``core.batch.batch_shard_factor`` through an accelerated
     backend for the dynamic extent of the context (``"numpy"`` is a
     no-op).  Used by tests to run real columnar sweeps through the
-    kernels and assert byte-parity, and by on-device sweeps where the
-    divisibility pass should stay on the accelerator."""
+    kernels and assert byte-parity."""
     from repro.core import batch as B
 
     if backend == "numpy":

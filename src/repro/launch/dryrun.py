@@ -33,7 +33,7 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import NamedSharding
 
 from repro.configs import SHAPES, cells, get_config, skipped_cells
 from repro.core import factors as FA
@@ -41,11 +41,9 @@ from repro.core import predictor as PR
 from repro.core import xla_metrics as XM
 from repro.core.spec import FULL_TRAIN
 from repro.launch import mesh as M
+from repro.launch.train import train_program
 from repro.mesh_ctx import mesh_axis_sizes, mesh_context
 from repro.models import build_model
-from repro.models import param as PM
-from repro.train import OptimizerConfig, TrainState, make_train_step
-from repro.train.optimizer import opt_state_specs
 
 from repro.calibrate.paths import dryrun_dir
 
@@ -61,45 +59,25 @@ def input_specs(arch: str, shape_name: str) -> dict:
     return model.batch_spec(SHAPES[shape_name])
 
 
-def _state_specs(model, opt_cfg):
-    params = model.param_specs()
-    mask = PM.trainable_mask(model.spec, FULL_TRAIN)
-    trainable, _ = PM.partition_params(params, mask)
-    opt = opt_state_specs(trainable, opt_cfg)
-    return TrainState(params=params, opt=opt,
-                      step=jax.ShapeDtypeStruct((), jnp.int32)), mask
-
-
 def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
-               rules_override=None, remat=None, opt_name=None):
-    """Lower + compile one cell; returns (record, compiled)."""
+               rules_override=None, remat=None):
+    """Lower + compile one cell; returns (record, compiled).  A train
+    cell compiles the launcher's step (:func:`train_program`)."""
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
     model = build_model(cfg)
     mesh = M.make_production_mesh(multi_pod=multi_pod)
     rules = {**M.arch_rules(cfg, shape.kind), **(rules_override or {})}
-    opt_cfg = OptimizerConfig(name=opt_name or cfg.optimizer)
+    remat = remat or cfg.remat
 
     with mesh_context(mesh, rules):
         psh = M.param_shardings(model, mesh)
         if shape.kind == "train":
-            state_specs, mask = _state_specs(model, opt_cfg)
-            axes_tree = model.param_axes()
-            t_axes = jax.tree.map(lambda m, ax: ax if m else None, mask,
-                                  axes_tree)
-            t_specs, _ = PM.partition_params(state_specs.params, mask)
-            osh = M.opt_shardings(model, mesh, t_specs, opt_cfg, t_axes)
-            zsh = M.zero_grad_shardings(mesh, t_specs, t_axes)
-            batch = model.batch_spec(shape)
-            bsh = M.batch_shardings(mesh, batch)
-            step_fn = make_train_step(model, FULL_TRAIN, opt_cfg,
-                                      zero_shardings=zsh, remat=remat)
-            state_sh = TrainState(params=psh, opt=osh,
-                                  step=NamedSharding(mesh, P()))
-            jitted = jax.jit(step_fn,
-                             in_shardings=(state_sh, bsh),
-                             donate_argnums=(0,))
-            lowered = jitted.lower(state_specs, batch)
+            init, step, _ = train_program(model, shape, mesh, remat=remat,
+                                          grad_accum=1)
+            state = jax.eval_shape(init,
+                                   jax.ShapeDtypeStruct((2,), jnp.uint32))
+            lowered = step.lower(state, model.batch_spec(shape))
         elif shape.kind == "prefill":
             batch = model.batch_spec(shape)
             bsh = M.batch_shardings(mesh, batch)
@@ -142,9 +120,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool = False,
     # the paper framework's prediction for the same cell
     ctx = FA.PredictContext(
         mesh_shape=mesh_axis_sizes(mesh), rules=rules,
-        optimizer=opt_cfg.name, fsdp=cfg.fsdp,
-        master_fp32=opt_cfg.name != "adafactor",
-        remat=remat or cfg.remat,
+        optimizer=cfg.optimizer, fsdp=cfg.fsdp,
+        master_fp32=cfg.optimizer != "adafactor",
+        remat=remat,
         global_batch=shape.global_batch, seq_len=shape.seq_len,
         enc_seq=int(shape.seq_len * cfg.encdec.enc_seq_ratio)
         if cfg.encdec else 0,
@@ -235,7 +213,11 @@ def main():
                 cmd = [sys.executable, "-m", "repro.launch.dryrun",
                        "--arch", arch, "--shape", shape_name,
                        "--out", args.out] + (["--multi-pod"] if mp else [])
-                r = subprocess.run(cmd, capture_output=True, text=True)
+                # each child compiles on XLA:CPU's forced host devices;
+                # pinning the platform keeps it off any attached chip
+                r = subprocess.run(cmd, capture_output=True, text=True,
+                                   env={**os.environ,
+                                        "JAX_PLATFORMS": "cpu"})
                 tail = (r.stdout + r.stderr).strip().splitlines()
                 print(tail[-1] if tail else "(no output)")
                 if r.returncode != 0:

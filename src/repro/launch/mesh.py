@@ -26,20 +26,11 @@ from repro.models.registry import Model
 from repro.train.optimizer import OptimizerConfig, opt_state_specs
 
 
-def _auto_axis_types(n: int) -> dict:
-    """`axis_types` kwarg for jax.make_mesh on jax versions that have it
-    (jax.sharding.AxisType landed after 0.4.x; Auto is that default
-    behaviour, so omitting the kwarg is equivalent on older versions)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def divisors(n: int) -> list[int]:
@@ -130,10 +121,14 @@ def cp_degree(mesh_shape: dict) -> int:
     return int(mesh_shape.get(CONTEXT_AXIS, 1))
 
 
-def make_smoke_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Tiny mesh for CPU tests (exercises the same code paths)."""
+def make_smoke_mesh(data: int = 1, model: int = 1,
+                    devices=None) -> Mesh:
+    """A (data, model) mesh over the first ``data * model`` of
+    ``devices`` (default: the local devices) — the launcher's mesh on a
+    chip, a tiny one in CPU tests."""
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_auto_axis_types(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=devices)
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.spec import (ActTerm, LayerSpec, ParamSpec,
                              AXIS_EMBED, AXIS_EXPERTS, AXIS_EXPERT_BUF,
@@ -188,7 +187,7 @@ def moe_forward(p: dict, x: jax.Array, meta: dict) -> tuple[jax.Array, jax.Array
         ep = sizes.get("model", 1)
         # tokens stay 3-D: batch over data, seq over model (matches SP), so
         # the shard_map boundary never reshapes across shardings.
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_ep_local, top_k=top_k, n_experts=E, cf=cf,
                               ep_axis="model", ep_size=ep),
             mesh=mesh,
@@ -196,7 +195,7 @@ def moe_forward(p: dict, x: jax.Array, meta: dict) -> tuple[jax.Array, jax.Array
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P(batch_axes, "model", None),
-            check_rep=False)
+            check_vma=False)
         y = fn(x, p["router"], p["wg"], p["wu"], p["wd"])
         # aux loss from a (cheap, duplicated) global router eval so the
         # scalar is well-defined across shards (3-D einsum: no reshape).

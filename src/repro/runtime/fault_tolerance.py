@@ -57,6 +57,9 @@ class ResilientTrainer:
     restarts: int = 0                       # lifetime stat (never resets)
     _consecutive_failures: int = 0          # the abort budget
     straggler_events: list = field(default_factory=list)
+    # wall seconds of each completed step, host clock, up to the metrics
+    # reaching the host (the straggler detector's input)
+    step_seconds: list = field(default_factory=list)
 
     def _batch(self, step: int):
         if self.make_batch is not None:
@@ -78,6 +81,11 @@ class ResilientTrainer:
                 if self.failure_injector and self.failure_injector(step):
                     raise RuntimeError(f"injected failure at step {step}")
                 state, metrics = self.train_step(state, batch)
+                # reading the metrics waits for the step, so the clock
+                # covers its device time, and an asynchronous device
+                # failure surfaces here, inside the restart path
+                metrics = {k: float(np.asarray(v))
+                           for k, v in metrics.items()}
             except Exception:
                 # `restarts` is the lifetime stat; the abort decision
                 # rides the CONSECUTIVE counter (reset on success), so a
@@ -98,9 +106,9 @@ class ResilientTrainer:
                 continue
             self._consecutive_failures = 0
             dt = time.monotonic() - t0
+            self.step_seconds.append(dt)
             self._track_stragglers(step, dt)
-            history.append({"step": step, **{k: float(np.asarray(v))
-                                             for k, v in metrics.items()}})
+            history.append({"step": step, **metrics})
             step += 1
             if step % self.fault_cfg.ckpt_every == 0:
                 self.checkpointer.save_async(step, state)

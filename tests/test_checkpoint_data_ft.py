@@ -122,6 +122,7 @@ def test_resilient_trainer_recovers_from_failure(tmp_path):
     state, history = trainer.run(_state(model), start_step=0, n_steps=10)
     assert trainer.restarts == 1
     assert int(state.step) >= 10
+    assert len(trainer.step_seconds) == len(history)   # failed: untimed
     assert all(np.isfinite(h["loss"]) for h in history)
     # failure at step 5 rolls back to the step-3 checkpoint and REPLAYS
     # steps 3-4 (deterministic pipeline -> identical batches), then
@@ -158,3 +159,6 @@ def test_resilient_trainer_straggler_detection(tmp_path):
         make_batch=lambda s: {})
     trainer.run(_state(model), start_step=0, n_steps=8)
     assert len(trainer.straggler_events) >= 1
+    # the detector's input is kept: one wall time per completed step
+    assert len(trainer.step_seconds) == 8
+    assert trainer.step_seconds[5] >= 0.75

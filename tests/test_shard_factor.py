@@ -48,7 +48,7 @@ def test_randomized_program_parity(backend):
         dims, axes, sizes, rules, extra = random_program(RNG, n_cells=17)
         ref = B.batch_shard_factor(dims, axes, sizes, rules, extra)
         got = K.shard_factor(dims, axes, sizes, rules, extra,
-                             backend=backend)
+                             backend=backend, interpret=True)
         assert got.dtype == np.int64
         assert np.array_equal(np.asarray(got), ref), \
             f"trial {trial}: {axes} rules={rules} extra={extra}"
@@ -72,7 +72,7 @@ def test_pallas_pads_partial_blocks():
     dims, axes, sizes, rules, extra = random_program(RNG, n_cells=7)
     ref = B.batch_shard_factor(dims, axes, sizes, rules, extra)
     got = K.shard_factor(dims, axes, sizes, rules, extra,
-                         backend="pallas", block=4)
+                         backend="pallas", block=4, interpret=True)
     assert np.array_equal(np.asarray(got), ref)
 
 
@@ -107,7 +107,7 @@ def test_use_backend_real_sweep_parity(backend):
                         global_batches=(8, 16), seq_lens=(512,),
                         microbatches=(1, 2), kind="train")
     ref = SW.SweepEngine().sweep(grid)
-    with K.use_backend(backend):
+    with K.use_backend(backend, interpret=True):
         got = SW.SweepEngine().sweep(grid)
     assert np.array_equal(got.columns.peak_bytes, ref.columns.peak_bytes)
     assert np.array_equal(got.columns.fits, ref.columns.fits)
@@ -119,7 +119,7 @@ def test_use_backend_restores_impl():
         assert B._shard_factor_impl is not None
     assert B._shard_factor_impl is None
     with pytest.raises(RuntimeError):
-        with K.use_backend("pallas"):
+        with K.use_backend("pallas", interpret=True):
             assert B._shard_factor_impl is not None
             raise RuntimeError("boom")
     assert B._shard_factor_impl is None
