@@ -57,6 +57,7 @@ from repro.core import predictor as PR
 from repro.core import sweep as SW
 from repro.core.spec import FULL_TRAIN, dtype_bytes
 from repro.mesh_ctx import CONTEXT_AXIS, PIPE_AXIS
+from repro.spans import span
 
 I64 = np.int64
 
@@ -936,7 +937,8 @@ def sweep_columnar(engine, grid, jobs: int = 1) -> "SW.SweepResults":
     grid.check_offload()
     grid.check_assembly()
     live_mode = grid.assembly == "liveness"
-    cols = build_columns(grid)
+    with span("plan.columns"):
+        cols = build_columns(grid)
     if cols.n == 0:
         return SW.SweepResults(grid=grid, results=[],
                                elapsed_s=time.perf_counter() - t0)
@@ -1004,7 +1006,8 @@ def sweep_columnar(engine, grid, jobs: int = 1) -> "SW.SweepResults":
             sel = np.isin(m_c, mesh_ids)
             if not sel.any():
                 continue
-            env = _knob_env(cfg, cols, pp)
+            with span("plan.columns"):
+                env = _knob_env(cfg, cols, pp)
             plan = engine._stage_plan(arch, grid.policy, pp)
             lidx = np.full(len(cols.meshes), -1, I64)
             lidx[mesh_ids] = np.arange(len(mesh_ids), dtype=I64)
@@ -1028,10 +1031,11 @@ def sweep_columnar(engine, grid, jobs: int = 1) -> "SW.SweepResults":
             if live_mode:
                 b_slack = np.zeros_like(best)
             for s, srows in enumerate(plan.stages):
-                tabs = _stage_tables_jobs(
-                    cfg, model, list(srows), rules, rep_ctx, cols, env,
-                    profile, opt_res, remat_eval, mesh_ids, s, pp, jobs,
-                    drafts)
+                with span("plan.tables"):
+                    tabs = _stage_tables_jobs(
+                        cfg, model, list(srows), rules, rep_ctx, cols, env,
+                        profile, opt_res, remat_eval, mesh_ids, s, pp,
+                        jobs, drafts)
                 # schedule stash: GPipe stages hold all m microbatch
                 # activation sets, 1F1B stage s holds min(pp - s, m)
                 stash = np.maximum(
@@ -1172,6 +1176,8 @@ def sweep_columnar(engine, grid, jobs: int = 1) -> "SW.SweepResults":
         per_remat = np.array([_intern(remat_tbl, remat_names, r)
                               for r in remat_res], I64)
         res_remat_c[sl] = per_remat[cols.remat_c[sl]]
-    return _finalize_results(grid, cols, t0, peak, pool_arr, draft_arr,
-                             hit_arr, off_arr, opt_names, remat_names,
-                             res_opt_c, res_remat_c, slack_arr)
+    with span("plan.finalize"):
+        return _finalize_results(grid, cols, t0, peak, pool_arr,
+                                 draft_arr, hit_arr, off_arr, opt_names,
+                                 remat_names, res_opt_c, res_remat_c,
+                                 slack_arr)

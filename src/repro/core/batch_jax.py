@@ -52,6 +52,7 @@ from repro.core import batch as B
 from repro.core import planner as PL
 from repro.core import sweep as SW
 from repro.mesh_ctx import PIPE_AXIS
+from repro.spans import span
 
 I64 = np.int64
 
@@ -300,16 +301,20 @@ def _group_tables(engine, grid, cols, cfg, model, rows, rules, rep_ctx,
     hit = cache.get(key)
     if hit is not None:
         return hit
-    plan = engine._stage_plan(arch, grid.policy, pp)
-    folded = []
-    for s, srows in enumerate(plan.stages):
-        tabs = B._stage_tables_jobs(
-            cfg, model, list(srows), rules, rep_ctx, cols, env, profile,
-            opt_res, remat_eval, mesh_ids, s, pp, jobs, drafts)
-        folded.append(_fold_stage(tabs, profile, env, pp, s,
-                                  liveness=grid.assembly == "liveness"))
-    stacked = {k: np.stack([f[k] for f in folded])
-               for k in folded[0]}
+    with span("plan.tables"):
+        plan = engine._stage_plan(arch, grid.policy, pp)
+        folded = []
+        for s, srows in enumerate(plan.stages):
+            tabs = B._stage_tables_jobs(
+                cfg, model, list(srows), rules, rep_ctx, cols, env,
+                profile, opt_res, remat_eval, mesh_ids, s, pp, jobs,
+                drafts)
+            with span("plan.fold"):
+                folded.append(_fold_stage(
+                    tabs, profile, env, pp, s,
+                    liveness=grid.assembly == "liveness"))
+        stacked = {k: np.stack([f[k] for f in folded])
+                   for k in folded[0]}
     cache[key] = stacked
     return stacked
 
@@ -330,7 +335,8 @@ def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
     grid.check_offload()
     grid.check_assembly()
     live_mode = grid.assembly == "liveness"
-    cols = B.build_columns(grid)
+    with span("plan.columns"):
+        cols = B.build_columns(grid)
     if cols.n == 0:
         return SW.SweepResults(grid=grid, results=[],
                                elapsed_s=time.perf_counter() - t0)
@@ -399,7 +405,8 @@ def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
         slack_v = view(slack_arr) if live_mode else None
         for pp in sorted(set(pp_of.tolist())):
             mesh_ids = np.flatnonzero(pp_of == pp)
-            env = B._knob_env(cfg, cols, pp)
+            with span("plan.columns"):
+                env = B._knob_env(cfg, cols, pp)
             serve_grp = env["_serve_expanded"]
             t2 = (t2_full_i if env["_expanded"]
                   else t2_srv_i if serve_grp else t2_flat_i)
@@ -419,22 +426,23 @@ def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
                 else np.zeros(0, I64)
             carry0 = tuple(np.zeros((n_lm, inner), I64)
                            for _ in range(6))
-            with jax.enable_x64(True):
+            with jax.enable_x64(True), span("plan.compose"):
                 best, bp, bd, bh, bo, bs = compose(
                     carry0, tabs, (c_aff, c_b, c_ctr, c_ho, t2),
                     has_profile=profile is not None,
                     serve=bool(serve_grp), off=bool(off_grp),
                     assembly=grid.assembly, kind=cols.kind)
-                best = np.asarray(best)
-                peak_v[:, mesh_ids, :] = best
-                if serve_grp:
-                    pool_v[:, mesh_ids, :] = np.asarray(bp)
-                    draft_v[:, mesh_ids, :] = np.asarray(bd)
-                    hit_v[:, mesh_ids, :] = np.asarray(bh)
-                if off_grp:
-                    off_v[:, mesh_ids, :] = np.asarray(bo)
-                if live_mode:
-                    slack_v[:, mesh_ids, :] = np.asarray(bs)
+                with span("plan.to_host"):
+                    best = np.asarray(best)
+                    peak_v[:, mesh_ids, :] = best
+                    if serve_grp:
+                        pool_v[:, mesh_ids, :] = np.asarray(bp)
+                        draft_v[:, mesh_ids, :] = np.asarray(bd)
+                        hit_v[:, mesh_ids, :] = np.asarray(bh)
+                    if off_grp:
+                        off_v[:, mesh_ids, :] = np.asarray(bo)
+                    if live_mode:
+                        slack_v[:, mesh_ids, :] = np.asarray(bs)
         if profile is not None:
             # per-chip calibration offset: stage-constant, so adding it
             # after the stage max (and outside the strictly-greater
@@ -448,6 +456,8 @@ def sweep_columnar_jax(engine, grid, jobs: int = 1) -> "SW.SweepResults":
         per_remat = np.array([B._intern(remat_tbl, remat_names, r)
                               for r in remat_res], I64)
         res_remat_c[sl] = per_remat[cols.remat_c[sl]]
-    return B._finalize_results(grid, cols, t0, peak, pool_arr, draft_arr,
-                               hit_arr, off_arr, opt_names, remat_names,
-                               res_opt_c, res_remat_c, slack_arr)
+    with span("plan.finalize"):
+        return B._finalize_results(grid, cols, t0, peak, pool_arr,
+                                   draft_arr, hit_arr, off_arr, opt_names,
+                                   remat_names, res_opt_c, res_remat_c,
+                                   slack_arr)

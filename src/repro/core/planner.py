@@ -23,6 +23,7 @@ from typing import Optional
 from repro.core import factors as F
 from repro.core import predictor as PR
 from repro.core.spec import FULL_TRAIN, TrainPolicy
+from repro.spans import span
 
 GiB = 1024 ** 3
 
@@ -452,10 +453,11 @@ def plan_min_chips(arch: str, shape_name, chips=(4, 8, 16, 32, 64),
     from repro.core import search as SR
     from repro.core import sweep as SW
     shape = _resolve_shape(shape_name)
-    grid = _search_grid(arch, shape, chips, chip, policy, backend,
-                        headroom, allow_pp, max_pp, allow_ep, max_ep,
-                        allow_cp, max_cp, microbatches, schedules,
-                        profile)
+    with span("plan.grid"):
+        grid = _search_grid(arch, shape, chips, chip, policy, backend,
+                            headroom, allow_pp, max_pp, allow_ep, max_ep,
+                            allow_cp, max_cp, microbatches, schedules,
+                            profile)
     if grid is None:
         return None
     engine = engine or SW.SweepEngine()
@@ -495,10 +497,11 @@ def plan_frontier(arch: str, shape_name, chips=(4, 8, 16, 32, 64),
             if gb == 1:
                 break
             gb //= 2
-    grid = _search_grid(arch, shape, chips, chip, policy, backend,
-                        headroom, allow_pp, max_pp, allow_ep, max_ep,
-                        allow_cp, max_cp, microbatches, schedules,
-                        profile, global_batches=tuple(global_batches))
+    with span("plan.grid"):
+        grid = _search_grid(arch, shape, chips, chip, policy, backend,
+                            headroom, allow_pp, max_pp, allow_ep, max_ep,
+                            allow_cp, max_cp, microbatches, schedules,
+                            profile, global_batches=tuple(global_batches))
     if grid is None:
         return []
     engine = engine or SW.SweepEngine()

@@ -60,7 +60,9 @@ monotone knob safely").
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, replace
+
+from repro.spans import span
 
 __all__ = [
     "SearchStats", "static_floor_bytes", "min_chips_search",
@@ -77,8 +79,6 @@ class SearchStats:
     cells_evaluated: int = 0     # cells actually swept
     cells_pruned: int = 0        # cells skipped via bounds / early exit
     probes: int = 0              # scalar report() evaluations
-    bound_evals: int = 0         # statics-floor bound computations
-    notes: list = field(default_factory=list)
 
     @property
     def total_cells(self) -> int:
@@ -97,8 +97,6 @@ class SearchStats:
         self.cells_evaluated += other.cells_evaluated
         self.cells_pruned += other.cells_pruned
         self.probes += other.probes
-        self.bound_evals += other.bound_evals
-        self.notes.extend(other.notes)
 
 
 # ---------------------------------------------------------------------------
@@ -229,31 +227,31 @@ def min_chips_search(grid, engine=None, stats: SearchStats = None,
     """
     from repro.core import sweep as SW
 
-    engine = engine or SW.SweepEngine()
-    stats = stats if stats is not None else SearchStats()
-    floor = _floor_for(grid)
-    budgets = _budgets(grid)
-    by_n = _by_count(grid)
-    stats.bound_evals += len(by_n)
-    best = None
-    for n in sorted(by_n):
-        meshes = by_n[n]
-        chips_ok = tuple(c for c, b in budgets.items()
-                         if floor // n <= b) or ()
-        full = _slice(grid, meshes).size()
-        if best is not None or not chips_ok:
-            stats.cells_pruned += full
-            continue
-        sl = _slice(grid, meshes, chip=chips_ok)
-        res = engine.sweep(sl, engine=compute_engine)
-        stats.cells_evaluated += len(res)
-        stats.cells_pruned += full - len(res)
-        best = res.min_chips()
-        # keep looping only to account remaining pruned cells
-    if oracle:
-        ref = engine.sweep(grid, engine=compute_engine).min_chips()
-        _assert_same_cell(best, ref, "min_chips")
-    return best
+    with span("plan.search"):
+        engine = engine or SW.SweepEngine()
+        stats = stats if stats is not None else SearchStats()
+        floor = _floor_for(grid)
+        budgets = _budgets(grid)
+        by_n = _by_count(grid)
+        best = None
+        for n in sorted(by_n):
+            meshes = by_n[n]
+            chips_ok = tuple(c for c, b in budgets.items()
+                             if floor // n <= b) or ()
+            full = _slice(grid, meshes).size()
+            if best is not None or not chips_ok:
+                stats.cells_pruned += full
+                continue
+            sl = _slice(grid, meshes, chip=chips_ok)
+            res = engine.sweep(sl, engine=compute_engine)
+            stats.cells_evaluated += len(res)
+            stats.cells_pruned += full - len(res)
+            best = res.min_chips()
+            # keep looping only to account remaining pruned cells
+        if oracle:
+            ref = engine.sweep(grid, engine=compute_engine).min_chips()
+            _assert_same_cell(best, ref, "min_chips")
+        return best
 
 
 def _assert_same_cell(got, ref, what: str) -> None:
@@ -286,43 +284,43 @@ def frontier_search(grid, engine=None, stats: SearchStats = None,
     from repro.core import sweep as SW
     from repro.core.sweep import _seq
 
-    engine = engine or SW.SweepEngine()
-    stats = stats if stats is not None else SearchStats()
-    floor = _floor_for(grid)
-    budgets = _budgets(grid)
-    by_n = _by_count(grid)
-    stats.bound_evals += len(by_n)
-    gbs = sorted(set(int(g) for g in _seq(grid.global_batches)),
-                 reverse=True)
-    out = []
-    for n in sorted(by_n):
-        meshes = by_n[n]
-        chips_ok = tuple(c for c, b in budgets.items() if floor // n <= b)
-        if not chips_ok:
-            stats.cells_pruned += _slice(grid, meshes).size()
-            continue
-        found = False
-        for gb in gbs:
-            full = _slice(grid, meshes, global_batches=(gb,)).size()
-            if found:
-                stats.cells_pruned += full
+    with span("plan.search"):
+        engine = engine or SW.SweepEngine()
+        stats = stats if stats is not None else SearchStats()
+        floor = _floor_for(grid)
+        budgets = _budgets(grid)
+        by_n = _by_count(grid)
+        gbs = sorted(set(int(g) for g in _seq(grid.global_batches)),
+                     reverse=True)
+        out = []
+        for n in sorted(by_n):
+            meshes = by_n[n]
+            chips_ok = tuple(c for c, b in budgets.items() if floor // n <= b)
+            if not chips_ok:
+                stats.cells_pruned += _slice(grid, meshes).size()
                 continue
-            sl = _slice(grid, meshes, chip=chips_ok,
-                        global_batches=(gb,))
-            res = engine.sweep(sl, engine=compute_engine)
-            stats.cells_evaluated += len(res)
-            stats.cells_pruned += full - len(res)
-            if res.fit_count:
-                out.append((n, gb))
-                found = True
-        # chip types dropped by the floor hold no fitting cells, so the
-        # per-count max over the kept types equals the full grid's
-    if oracle:
-        ref = engine.sweep(grid, engine=compute_engine).frontier()
-        if out != ref:
-            raise AssertionError(
-                f"frontier: pruned={out!r} != exhaustive={ref!r}")
-    return out
+            found = False
+            for gb in gbs:
+                full = _slice(grid, meshes, global_batches=(gb,)).size()
+                if found:
+                    stats.cells_pruned += full
+                    continue
+                sl = _slice(grid, meshes, chip=chips_ok,
+                            global_batches=(gb,))
+                res = engine.sweep(sl, engine=compute_engine)
+                stats.cells_evaluated += len(res)
+                stats.cells_pruned += full - len(res)
+                if res.fit_count:
+                    out.append((n, gb))
+                    found = True
+            # chip types dropped by the floor hold no fitting cells, so the
+            # per-count max over the kept types equals the full grid's
+        if oracle:
+            ref = engine.sweep(grid, engine=compute_engine).frontier()
+            if out != ref:
+                raise AssertionError(
+                    f"frontier: pruned={out!r} != exhaustive={ref!r}")
+        return out
 
 
 # ---------------------------------------------------------------------------

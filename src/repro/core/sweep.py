@@ -61,6 +61,7 @@ from repro.core import report as RPT
 from repro.core.parser import parse_model
 from repro.core.spec import (FULL_TRAIN, LLAVA_STAGE1, LLAVA_STAGE2,
                              TrainPolicy)
+from repro.spans import span
 
 GiB = 1024 ** 3
 
@@ -717,9 +718,10 @@ class SweepEngine:
         if hit is None:
             from repro.configs import get_config
             from repro.models import build_model
-            cfg = get_config(arch)
-            model = build_model(cfg)
-            rows = parse_model(model.spec, policy)
+            with span("plan.arch"):
+                cfg = get_config(arch)
+                model = build_model(cfg)
+                rows = parse_model(model.spec, policy)
             hit = self._arch[key] = (cfg, model, rows)
         return hit
 
@@ -729,7 +731,8 @@ class SweepEngine:
         if hit is None:
             from repro.core import stages as ST
             _, _, rows = self._arch_state(arch, policy)
-            hit = self._stages[key] = ST.partition(rows, pp)
+            with span("plan.arch"):
+                hit = self._stages[key] = ST.partition(rows, pp)
         return hit
 
     def predict_cell(self, arch: str, policy: TrainPolicy,
@@ -955,46 +958,47 @@ class SweepEngine:
         1 splits the columnar component stage over worker threads
         (mesh-chunked; results are order-identical).
         """
-        if mode not in ("columnar", "cell"):
-            raise ValueError(
-                f"unknown sweep mode {mode!r}; use 'columnar' or 'cell'")
-        if engine not in ("numpy", "jax"):
-            raise ValueError(
-                f"unknown sweep engine {engine!r}; use 'numpy' or 'jax'")
-        if engine == "jax":
-            if mode == "cell":
+        with span("plan.sweep"):
+            if mode not in ("columnar", "cell"):
                 raise ValueError(
-                    "engine='jax' lowers the columnar path; it cannot "
-                    "drive mode='cell' (use engine='numpy')")
-            if grid.keep_predictions:
+                    f"unknown sweep mode {mode!r}; use 'columnar' or 'cell'")
+            if engine not in ("numpy", "jax"):
                 raise ValueError(
-                    "engine='jax' does not materialize PredictedMemory "
-                    "breakdowns; use engine='numpy' with "
-                    "keep_predictions=True")
-            if grid.residual_model is not None:
-                raise ValueError(
-                    "engine='jax' does not apply learned residual "
-                    "models; use engine='numpy' (the residual grid "
-                    "routes through the cell path)")
-            from repro.core import batch_jax as BJ
-            return BJ.sweep_columnar_jax(self, grid, jobs=jobs)
-        if mode == "columnar" and not grid.keep_predictions \
-                and grid.residual_model is None:
-            try:
-                from repro.core import batch as B
-            except ImportError:          # no numpy -> reference path
-                B = None
-            if B is not None:
-                return B.sweep_columnar(self, grid, jobs=jobs)
-        t0 = time.perf_counter()
-        results = [self.evaluate(cell, grid.policy, grid.headroom,
-                                 grid.keep_predictions,
-                                 profile=grid.profile,
-                                 assembly=grid.assembly,
-                                 residual=grid.residual_model)
-                   for cell in grid.cells()]
-        return SweepResults(grid=grid, results=results,
-                            elapsed_s=time.perf_counter() - t0)
+                    f"unknown sweep engine {engine!r}; use 'numpy' or 'jax'")
+            if engine == "jax":
+                if mode == "cell":
+                    raise ValueError(
+                        "engine='jax' lowers the columnar path; it cannot "
+                        "drive mode='cell' (use engine='numpy')")
+                if grid.keep_predictions:
+                    raise ValueError(
+                        "engine='jax' does not materialize PredictedMemory "
+                        "breakdowns; use engine='numpy' with "
+                        "keep_predictions=True")
+                if grid.residual_model is not None:
+                    raise ValueError(
+                        "engine='jax' does not apply learned residual "
+                        "models; use engine='numpy' (the residual grid "
+                        "routes through the cell path)")
+                from repro.core import batch_jax as BJ
+                return BJ.sweep_columnar_jax(self, grid, jobs=jobs)
+            if mode == "columnar" and not grid.keep_predictions \
+                    and grid.residual_model is None:
+                try:
+                    from repro.core import batch as B
+                except ImportError:          # no numpy -> reference path
+                    B = None
+                if B is not None:
+                    return B.sweep_columnar(self, grid, jobs=jobs)
+            t0 = time.perf_counter()
+            results = [self.evaluate(cell, grid.policy, grid.headroom,
+                                     grid.keep_predictions,
+                                     profile=grid.profile,
+                                     assembly=grid.assembly,
+                                     residual=grid.residual_model)
+                       for cell in grid.cells()]
+            return SweepResults(grid=grid, results=results,
+                                elapsed_s=time.perf_counter() - t0)
 
 
 def sweep(grid: SweepGrid, engine=None,

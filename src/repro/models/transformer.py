@@ -80,6 +80,7 @@ def lm_spec(cfg: ArchConfig, name: str = "language_model") -> ModuleSpec:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attn")
 def _attn_apply(cfg: ArchConfig, bp: dict, h: jax.Array,
                 positions: Optional[jax.Array], chunk: int) -> jax.Array:
     if cfg.mla:
@@ -105,7 +106,9 @@ def _block_apply(cfg: ArchConfig, moe_block: bool, bp: dict, x: jax.Array,
             y = y + L.mlp(bp["dense_ffn"], h)
         x = x + y
     else:
-        x = x + L.mlp(bp["ffn"], h)
+        with jax.named_scope("mlp"):
+            y = L.mlp(bp["ffn"], h)
+        x = x + y
     return x, aux
 
 
@@ -196,6 +199,7 @@ def lm_logits(cfg: ArchConfig, p: dict, hidden: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("loss")
 def chunked_xent(cfg: ArchConfig, p: dict, hidden: jax.Array,
                  labels: jax.Array, chunk: int = LOSS_CHUNK):
     """hidden: (B, S, D); labels: (B, S) with -100 = masked.

@@ -283,13 +283,11 @@ def test_monotone_max_synthetic_ladders():
 
 def test_search_stats_merge():
     a = SR.SearchStats(cells_evaluated=3, cells_pruned=7, probes=2)
-    b = SR.SearchStats(cells_evaluated=1, cells_pruned=9, probes=0,
-                       bound_evals=4)
+    b = SR.SearchStats(cells_evaluated=1, cells_pruned=9, probes=3)
     a.merge(b)
-    assert (a.cells_evaluated, a.cells_pruned, a.probes,
-            a.bound_evals) == (4, 16, 2, 4)
+    assert (a.cells_evaluated, a.cells_pruned, a.probes) == (4, 16, 5)
     assert a.total_cells == 20
-    assert a.reduction == 20 / 6
+    assert a.reduction == 20 / 9
     assert SR.SearchStats().reduction == float("inf")
 
 
