@@ -224,12 +224,21 @@ def _chunk_layout(x, chunk):
     return x.reshape(B, n, chunk, h, d).swapaxes(0, 1), n
 
 
+def _tile_live(qi, ci, q_chunk, kv_chunk, q_offset):
+    """Whether the causal mask of q chunk ``qi`` against KV chunk ``ci``
+    has a True entry: the chunk's first key is at or before its last
+    query.  A dead tile lies after the diagonal in KV order, so every
+    row's running max is already finite and skipping it changes nothing."""
+    return ci * kv_chunk <= q_offset + qi * q_chunk + q_chunk - 1
+
+
 def _flash_fwd_impl(q, k, v, causal, chunk, q_offset):
     """Two-level blocked online-softmax attention.
 
     Blocks over BOTH the query and the KV sequence dims so the largest live
     score tensor is (B, q_chunk, Hkv, G, kv_chunk) — the lowered-HLO twin of
-    the Pallas kernel's VMEM tiling.  Returns (out, lse) with
+    the Pallas kernel's VMEM tiling.  Under the causal mask a tile with no
+    unmasked entry is skipped (``_tile_live``).  Returns (out, lse) with
     lse: (B, Sq, Hkv, G) fp32.
     """
     B, Sq, H, Dq = q.shape
@@ -253,21 +262,30 @@ def _flash_fwd_impl(q, k, v, causal, chunk, q_offset):
         q_pos = q_offset + qi * q_chunk + jnp.arange(q_chunk)
 
         def kv_body(carry, kv_in):
-            m, l, acc = carry
             ci, kci, vci = kv_in
-            kv_pos = ci * kv_chunk + jnp.arange(kv_chunk)
-            s = jnp.einsum("bshgd,bchd->bshgc", qci,
-                           kci.astype(jnp.float32))
-            mask = kv_pos[None, :] < Skv
-            if causal:
-                mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-            s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l * alpha + p.sum(axis=-1)
-            pv = jnp.einsum("bshgc,bchd->bshgd", p, vci.astype(jnp.float32))
-            return (m_new, l_new, acc * alpha[..., None] + pv), None
+
+            @jax.named_scope("tile")
+            def tile(carry):
+                m, l, acc = carry
+                kv_pos = ci * kv_chunk + jnp.arange(kv_chunk)
+                s = jnp.einsum("bshgd,bchd->bshgc", qci,
+                               kci.astype(jnp.float32))
+                mask = kv_pos[None, :] < Skv
+                if causal:
+                    mask = mask & (kv_pos[None, :] <= q_pos[:, None])
+                s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[..., None])
+                l_new = l * alpha + p.sum(axis=-1)
+                pv = jnp.einsum("bshgc,bchd->bshgd", p,
+                                vci.astype(jnp.float32))
+                return m_new, l_new, acc * alpha[..., None] + pv
+
+            if not causal:
+                return tile(carry), None
+            live = _tile_live(qi, ci, q_chunk, kv_chunk, q_offset)
+            return jax.lax.cond(live, tile, lambda c: c, carry), None
 
         m0 = jnp.full((B, q_chunk, Hkv, G), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, q_chunk, Hkv, G), jnp.float32)
@@ -304,7 +322,8 @@ def _flash_bwd(causal, chunk, q_offset, res, dout):
 
     Outer scan walks q chunks carrying full-KV dk/dv accumulators
     (B, Skv_pad, Hkv, D) fp32; the inner scan walks kv chunks recomputing
-    the (q_chunk x kv_chunk) probability tile.
+    the (q_chunk x kv_chunk) probability tile.  A causally empty tile is
+    skipped and gives zero dk/dv parts.
     """
     q, k, v, out, lse = res
     B, Sq, H, Dq = q.shape
@@ -340,22 +359,34 @@ def _flash_bwd(causal, chunk, q_offset, res, dout):
 
         def kv_body(dq_c, kv_in):
             ci, kci, vci = kv_in
-            kv_pos = ci * kv_chunk + jnp.arange(kv_chunk)
-            s = jnp.einsum("bshgd,bchd->bshgc", qci,
-                           kci.astype(jnp.float32))
-            mask = kv_pos[None, :] < Skv
-            if causal:
-                mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-            s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
-            p = jnp.exp(s - lsec[..., None])               # (B,qc,Hkv,G,c)
-            dv_c = jnp.einsum("bshgc,bshgd->bchd", p, doci)
-            dp = jnp.einsum("bshgd,bchd->bshgc", doci,
-                            vci.astype(jnp.float32))
-            ds = p * (dp - deltci[..., None])
-            dq_c = dq_c + jnp.einsum("bshgc,bchd->bshgd", ds,
-                                     kci.astype(jnp.float32))
-            dk_c = jnp.einsum("bshgc,bshgd->bchd", ds, qci)
-            return dq_c, (dk_c, dv_c)
+
+            @jax.named_scope("tile")
+            def tile(dq_c):
+                kv_pos = ci * kv_chunk + jnp.arange(kv_chunk)
+                s = jnp.einsum("bshgd,bchd->bshgc", qci,
+                               kci.astype(jnp.float32))
+                mask = kv_pos[None, :] < Skv
+                if causal:
+                    mask = mask & (kv_pos[None, :] <= q_pos[:, None])
+                s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
+                p = jnp.exp(s - lsec[..., None])           # (B,qc,Hkv,G,c)
+                dv_c = jnp.einsum("bshgc,bshgd->bchd", p, doci)
+                dp = jnp.einsum("bshgd,bchd->bshgc", doci,
+                                vci.astype(jnp.float32))
+                ds = p * (dp - deltci[..., None])
+                dq_c = dq_c + jnp.einsum("bshgc,bchd->bshgd", ds,
+                                         kci.astype(jnp.float32))
+                dk_c = jnp.einsum("bshgc,bshgd->bchd", ds, qci)
+                return dq_c, (dk_c, dv_c)
+
+            def skip(dq_c):
+                return dq_c, (jnp.zeros((B, kv_chunk, Hkv, Dq), jnp.float32),
+                              jnp.zeros((B, kv_chunk, Hkv, Dv), jnp.float32))
+
+            if not causal:
+                return tile(dq_c)
+            live = _tile_live(qi, ci, q_chunk, kv_chunk, q_offset)
+            return jax.lax.cond(live, tile, skip, dq_c)
 
         dq0 = jnp.zeros((B, q_chunk, Hkv, G, Dq), jnp.float32)
         dq_c, (dk_parts, dv_parts) = jax.lax.scan(

@@ -72,6 +72,21 @@ def run_with_devices(code: str, n_devices: int = 4) -> str:
     return r.stdout
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_leaked_archs():
+    """Each test module starts with no runtime-registered architecture: a
+    file run before it on the same xdist worker (the benchmark's tests
+    register the configurations they build) must not add to
+    ``registered_archs()``, which the golden suite compares with its
+    snapshot files."""
+    from repro import configs
+    saved = dict(configs._RUNTIME)
+    configs._RUNTIME.clear()
+    yield
+    configs._RUNTIME.clear()
+    configs._RUNTIME.update(saved)
+
+
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)

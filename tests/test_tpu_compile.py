@@ -2,13 +2,16 @@
 (installed without a chip) must accept the programs the system runs on
 the device — every variant of the jitted columnar composition at int64
 table sizes, and the launcher's full-width smollm-360m train step, whose
-compiled footprint must fit the chip's HBM.  Nothing runs; a compile that
+compiled footprint must fit the chip's HBM and whose causal attention
+must keep its tile skip as a branch.  Nothing runs; a compile that
 passes is not a chip measurement.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every pytest-xdist worker
 imports this file, so only the worker that runs these tests may load it.
 """
+
+import re
 
 import numpy as np
 import pytest
@@ -116,3 +119,8 @@ def test_smollm_train_step_fits_v5e(topo):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert 0 < total < CHIPS["v5e"].hbm_bytes
+    # the causal tile skip stays a branch in each attention pass (forward,
+    # remat forward, backward): a select would run the dead tiles too
+    conds = re.findall(r'= .* conditional\(.*op_name="([^"]*)"',
+                       compiled.as_text())
+    assert len(conds) == 3 and all("/attn/" in n for n in conds), conds
