@@ -10,6 +10,10 @@ gradient and parameter change the reference follows once the window has
 closed, and go on into the window.  Every step gets its own seeded rows,
 put on the device as the step needs them; its metrics are read on the
 host after it, as ``ResilientTrainer.run`` does.  No checkpoint is saved.
+For the per-layer readers, set-up maps the compiled step's instructions
+to its named scopes (``facts["op_scopes"]``) and names its control-flow
+instructions for the trace's reduction; the check steps give
+the mean of each scalar the step reports (``facts["step_metrics"]``).
 
 Traffic keys: ``seq_len``, ``global_batch``, ``data`` (devices on the
 data axis; the model axis takes the rest), ``check_steps``,
@@ -27,21 +31,46 @@ import numpy as np
 
 import harness
 import seeded
+import span_reduce
 from harness import BenchError, Check, Outcome, log
 
-#: published config.json key -> the program's ArchConfig field
+#: published config.json key -> the program's ArchConfig field (a dotted
+#: path into its MLA or MoE group)
 WIDTHS = {"hidden_size": "d_model", "num_hidden_layers": "n_layers",
           "num_attention_heads": "n_heads",
           "num_key_value_heads": "n_kv_heads",
           "intermediate_size": "d_ff", "vocab_size": "vocab",
           "head_dim": "head_dim", "tie_word_embeddings": "tie_embeddings",
-          "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta"}
+          "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
+          "kv_lora_rank": "mla.kv_lora_rank",
+          "q_lora_rank": "mla.q_lora_rank",
+          "qk_nope_head_dim": "mla.qk_nope_head_dim",
+          "qk_rope_head_dim": "mla.qk_rope_head_dim",
+          "v_head_dim": "mla.v_head_dim",
+          "moe_intermediate_size": "moe.d_expert",
+          "num_experts_per_tok": "moe.top_k",
+          "n_shared_experts": "moe.n_shared_experts",
+          "first_k_dense_replace": "moe.n_dense_layers"}
+#: keys whose null in config.json is the program's 0 (no q compression)
+NULL_IS_ZERO = ("q_lora_rank",)
+
+
+def _program_value(cfg, field: str):
+    if field == "head_dim":
+        return cfg.resolved_head_dim
+    for part in field.split("."):
+        cfg = getattr(cfg, part, None)
+    return cfg
 
 
 def program_config(cell):
     """The program's ArchConfig for the configuration file: the named
     architecture with the file's ``overrides``, registered under the
-    configuration's name; it must carry the file's published sizes."""
+    configuration's name.  It must carry the file's value of every key
+    in ``WIDTHS``, cut or not.  ``n_routed_experts`` is not among them:
+    the program's ``moe.n_experts`` is the published count, the experts
+    one chip holds need a field of their own, and until then the
+    reference's shapes alone follow the file's count."""
     from repro.configs import get_config, register_config
     spec = cell.config["program"]
     cfg = dataclasses.replace(get_config(spec["arch"]),
@@ -52,9 +81,9 @@ def program_config(cell):
     for key, field in WIDTHS.items():
         if key not in cell.config:
             continue
-        want, got = cell.config[key], getattr(cfg, field)
-        if field == "head_dim":
-            got = cfg.resolved_head_dim
+        want, got = cell.config[key], _program_value(cfg, field)
+        if key in NULL_IS_ZERO and want is None:
+            want = 0
         if want != got:
             raise BenchError(f"the program's {field}={got!r} departs from "
                              f"{key}={want!r} of {cell.config_name}")
@@ -232,6 +261,17 @@ class Job:
         self.state = self.compiled = None
 
 
+def step_scopes(hlo_text: str) -> dict:
+    """The compiled step's leaf instructions -> its named scopes
+    (``span_reduce.SCOPES``), for the scope readers."""
+    scopes = span_reduce.op_scopes(hlo_text, span_reduce.SCOPES)
+    if not scopes:
+        log(f"scopes: the compiled step names none of {span_reduce.SCOPES}"
+            f" (a persistent-cache entry compiled before they were "
+            f"added?): the scope readers read nothing")
+    return scopes
+
+
 def _flat(tree) -> dict:
     import jax
     return dict(zip(seeded.paths(tree), jax.tree.leaves(tree)))
@@ -246,9 +286,18 @@ def program_readings(job: Job, steps: int) -> dict:
     """Drive the first ``steps`` steps and read what the reference
     follows: each loss, the first gradient as Adam's first moment holds
     it after step 1 (m = (1 - b1) g), and the change of the float32
-    master weights over all the steps."""
+    master weights over all the steps.  Also the mean over these steps
+    of each scalar in the step's metrics (``step_metrics``), which the
+    compiled step is wrapped to keep while they run; ``Job.step``
+    itself reads only the loss."""
     import jax
     scale = 1.0 / (1.0 - job.opt_cfg.b1)
+    seen, compiled = [], job.compiled
+
+    def recording(state, batch):
+        out = compiled(state, batch)
+        seen.append(out[1])
+        return out
 
     @jax.jit
     def grads(opt):
@@ -262,21 +311,32 @@ def program_readings(job: Job, steps: int) -> dict:
                 for k, x in _flat(opt).items() if k.endswith("/master")}
 
     losses, g = [], None
-    for i in range(steps):
-        losses.append(job.step(i))
-        if i == 0:
-            g = jax.device_get(grads(job.state.opt))
+    job.compiled = recording
+    try:
+        for i in range(steps):
+            losses.append(job.step(i))
+            if i == 0:
+                g = jax.device_get(grads(job.state.opt))
+    finally:
+        job.compiled = compiled
     moved = {k: float(v)
              for k, v in change(job.state.opt, job.words).items()}
+    seen = jax.device_get(seen)
     return {"loss": losses, "grads": g,
             "grad_norms": {k: float(np.linalg.norm(v)) for k, v in g.items()},
-            "change_norms": moved}
+            "change_norms": moved,
+            "step_metrics": {k: float(np.mean([m[k] for m in seen]))
+                             for k, v in seen[0].items()
+                             if np.ndim(v) == 0}}
 
 
 def run(cell, seed: int, seconds: float, trace: bool, devs) -> Outcome:
     import jax
     t = cell.traffic
     job = Job(cell, seed, devs)
+    text = job.compiled.as_text()
+    scopes, control = step_scopes(text), span_reduce.control_ops(text)
+    del text
     total, ma = memory_analysis_total(job.compiled)
     pred = job.report.peak_bytes
     log(f"memory: compiled step memory_analysis total {total} B: "
@@ -298,7 +358,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devs) -> Outcome:
         losses.append(job.step(steps + i))
         return job.tokens_per_step
 
-    w = harness.measure(cell.name, seed, seconds, trace, devs, one)
+    w = harness.measure(cell.name, seed, seconds, trace, devs, one,
+                        control=control)
     failed = sum(not math.isfinite(v) for v in prog["loss"] + losses)
     log(f"window: {w.calls} steps, {w.work} tokens in {w.elapsed} s, "
         f"compiles {w.compiles}, losses {losses[0]} .. {losses[-1]}")
@@ -320,6 +381,7 @@ def run(cell, seed: int, seconds: float, trace: bool, devs) -> Outcome:
     facts = dict(
         tokens_per_s=rate, chips=len(devs),
         device_kind=devs[0].device_kind, config=cell.config, seq=job.seq,
+        op_scopes=scopes, step_metrics=prog.get("step_metrics"),
         argument_bytes=ma.argument_size_in_bytes,
         predicted_argument_bytes=(pm.param_bytes + pm.opt_bytes
                                   + pm.input_bytes) if pm else None)

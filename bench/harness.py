@@ -299,14 +299,16 @@ class Window:
 
 
 def measure(workload: str, seed: int, seconds: float, trace: bool, devs,
-            one: Callable[[int], int]) -> Window:
+            one: Callable[[int], int], control=frozenset()) -> Window:
     """Set-up ends here: run the window (under the profiler when
-    ``trace``) and read the device as it closes."""
+    ``trace``) and read the device as it closes.  ``control``: the
+    control-flow instructions of the program the window runs, as
+    ``trace_reduce.reduce`` takes them."""
     counter = CompileCounter()
     setup_s, summary = setup_seconds(), None
     if trace:
         summary, (calls, work, elapsed) = traced(
-            workload, seed, lambda: run_window(seconds, one))
+            workload, seed, lambda: run_window(seconds, one), control)
     else:
         calls, work, elapsed = run_window(seconds, one)
     return Window(setup_s, calls, work, elapsed, counter.count,
@@ -318,7 +320,8 @@ def trace_dir(workload: str, seed: int) -> str:
     return os.path.join(base, "bench_trace", f"{workload}-{seed}")
 
 
-def traced(workload: str, seed: int, window: Callable[[], Any]) -> tuple:
+def traced(workload: str, seed: int, window: Callable[[], Any],
+           control=frozenset()) -> tuple:
     """Run ``window()`` under the profiler inside the span ``window``;
     returns (trace.Summary, what window() returned).  The trace is read
     and deleted before this returns.  ``BENCH_KEEP_TRACE`` names a file
@@ -339,6 +342,6 @@ def traced(workload: str, seed: int, window: Callable[[], Any]) -> tuple:
         keep = os.environ.get("BENCH_KEEP_TRACE")
         if keep:
             T.save(events, keep)
-        return T.reduce(events), out
+        return T.reduce(events, control), out
     finally:
         shutil.rmtree(path, ignore_errors=True)

@@ -13,14 +13,17 @@ reference with half of each batch left out, the mean taken over the
 rest (a planted fault).  A planning cell: for every ``--control-seeds``
 seed, the reference with its byte counts in int32 in the program's
 place, on what a run with that seed compares.  One JSON line per reading
-on standard output.  The benchmark's own runs never run this.
+on standard output; a training cell's gives each number beside the
+cell's limit and whether all of them hold (``correct``).  A seed given
+to more than one kind shares one exact reference, kept until its last
+use.  The benchmark's own runs never run this.
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import json
-import math
 import os
 import sys
 
@@ -30,19 +33,19 @@ sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 import harness  # noqa: E402
 import seeded  # noqa: E402
 
-NO_LIMITS = {"grad_diff": math.inf, "grad_norm_gap": math.inf,
-             "update_norm_gap": math.inf}
-
-
 def seeds(text: str) -> list:
     return [int(s) for s in text.split(",") if s]
 
 
-def emit(drv, kind: str, seed: int, prog: dict, ref: dict) -> None:
-    checks = drv.compare(prog, ref, NO_LIMITS)
+def emit(drv, limits: dict, kind: str, seed: int, prog: dict,
+         ref: dict) -> None:
+    checks = drv.compare(prog, ref, limits)
     print(json.dumps({"kind": kind, "seed": seed,
+                      "correct": all(c.ok for c in checks),
                       "loss_gap": drv.loss_gap(prog, ref),
-                      **{c.name: c.value for c in checks}}), flush=True)
+                      "compared": {c.name: {"value": c.value,
+                                            "limit": c.limit}
+                                   for c in checks}}), flush=True)
 
 
 def train_readings(cell, devs, args) -> None:
@@ -50,19 +53,24 @@ def train_readings(cell, devs, args) -> None:
     ref_mod = harness.load_module(cell.config["reference"])
     t = cell.traffic
     steps, rows = int(t["check_steps"]), int(t["rows_per_block"])
+    limits = t["limits"]
 
+    uses = collections.Counter([*args.seeds, *args.control_seeds,
+                                *args.fault_seeds])
     exact: dict = {}
 
     def reference(seed, **kw):
-        if not kw and seed in exact:
-            return exact[seed]
         batches = [seeded.token_batch(seed, i, int(t["global_batch"]),
                                       int(t["seq_len"]),
                                       int(cell.config["vocab_size"]))
                    for i in range(steps)]
-        out = ref_mod.run(cell.config, t["optimizer"], seed, batches,
-                          rows_per_block=rows, device=devs[0], **kw)
-        if not kw:
+        return ref_mod.run(cell.config, t["optimizer"], seed, batches,
+                           rows_per_block=rows, device=devs[0], **kw)
+
+    def exact_reference(seed):
+        out = exact.pop(seed) if seed in exact else reference(seed)
+        uses[seed] -= 1
+        if uses[seed]:
             exact[seed] = out
         return out
 
@@ -71,15 +79,14 @@ def train_readings(cell, devs, args) -> None:
         prog = drv.program_readings(job, steps)
         job.free()
         del job
-        emit(drv, "program", seed, prog, reference(seed))
-        exact.pop(seed)
+        emit(drv, limits, "program", seed, prog, exact_reference(seed))
     for seed in args.control_seeds:
-        emit(drv, "control", seed, reference(seed, lowp=True),
-             reference(seed))
+        emit(drv, limits, "control", seed, reference(seed, lowp=True),
+             exact_reference(seed))
     half = int(t["global_batch"]) // 2
     for seed in args.fault_seeds:
-        emit(drv, "half_batch", seed, reference(seed, keep_rows=half),
-             reference(seed))
+        emit(drv, limits, "half_batch", seed,
+             reference(seed, keep_rows=half), exact_reference(seed))
 
 
 def sweep_readings(cell, args) -> None:

@@ -15,12 +15,17 @@ benchmark's own spans; this module reads the program's beside them:
   clipped to the window;
 * ``idle_by_span(events)``: the first device's idle time in the window,
   each piece given to the innermost span that covers it;
-* ``op_scopes(hlo_text, scopes)``: HLO instruction name -> the first of
-  ``scopes`` found as a segment of its ``op_name``, bare (``attn``) or
-  wrapped by a transform (``jvp(loss)``);
-* ``scope_seconds(events, scopes)``: device time of the leaf ops (all
-  but ``while``, ``conditional`` and ``call``, whose bodies' ops are
-  events of their own) per scope, and the unscoped rest.
+* ``control_ops(hlo_text)``: the instructions whose opcode is ``while``,
+  ``conditional`` or ``call``: their bodies' ops are events of their
+  own, so their events are not leaf time (JAX names a ``lax.cond``
+  ``cond.N``, so the opcode decides and not the name);
+* ``op_scopes(hlo_text, scopes)``: leaf HLO instruction name -> the
+  first of ``scopes`` found as a segment of its ``op_name``, bare
+  (``attn``) or wrapped by a transform (``jvp(loss)``);
+* ``scope_seconds(events, scopes, control)``: device time of the leaf
+  ops (all but ``control``) per scope, and the unscoped rest;
+* ``scope_ms_per_step(facts, trace, scope)``: what the train cells'
+  ``<scope>_device_ms.train`` readers report.
 
 ``python bench/span_reduce.py TRACE [--hlo STEP.txt]`` prints these for
 a trace directory (``jax.profiler.trace``'s) or events saved by
@@ -39,10 +44,15 @@ import re
 import trace_reduce as T
 
 PREFIX = "plan."
-SCOPES = ("attn", "mlp", "loss")
-CONTROL = re.compile(r"^(while|conditional|call)(\.\d+)?$")
+#: the train step's named scopes (``moe`` opens none yet)
+SCOPES = ("attn", "moe", "mlp", "loss")
+CONTROL = ("while", "conditional", "call")
 _OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?'
                       r'op_name="([^"]*)"')
+#: an instruction's name and opcode: the first lower-case word followed
+#: by "(" after the result's shape (a layout's ``T(8,128)`` follows ":")
+_OPCODE = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?\s'
+                     r'([a-z][\w\-]*)\(')
 
 
 def load(trace_root: str) -> list:
@@ -159,16 +169,28 @@ def idle_by_span(events: list) -> dict:
     return out
 
 
+def control_ops(hlo_text: str) -> set:
+    """Names of the HLO instructions whose opcode is in ``CONTROL``."""
+    out = set()
+    for line in hlo_text.splitlines():
+        m = _OPCODE.match(line)
+        if m and m.group(2) in CONTROL:
+            out.add(m.group(1))
+    return out
+
+
 def op_scopes(hlo_text: str, scopes: tuple) -> dict:
-    """HLO instruction name -> the first of ``scopes`` that is a segment
-    of its ``op_name`` (``.../attn/dot_general``,
-    ``transpose(jvp(loss))/...``); instructions in none are left out."""
+    """Leaf HLO instruction name -> the first of ``scopes`` that is a
+    segment of its ``op_name`` (``.../attn/dot_general``,
+    ``transpose(jvp(loss))/...``); instructions in none, and the
+    ``control_ops``, are left out."""
     pats = [(sc, re.compile(r"(?:^|/)(?:[\w\-]+\()*" + re.escape(sc)
                             + r"\)*(?:/|$)")) for sc in scopes]
+    control = control_ops(hlo_text)
     out = {}
     for line in hlo_text.splitlines():
         m = _OP_NAME.match(line)
-        if not m:
+        if not m or m.group(1) in control:
             continue
         for scope, pat in pats:
             if pat.search(m.group(2)):
@@ -177,11 +199,24 @@ def op_scopes(hlo_text: str, scopes: tuple) -> dict:
     return out
 
 
-def scope_seconds(events: list, scopes: dict) -> dict:
+def scope_ms_per_step(facts: dict, trace, scope: str):
+    """Device milliseconds per window ``step`` of the leaf ops that
+    ``facts["op_scopes"]`` puts in ``scope``, from the trace's op times
+    (averaged over devices); None where there is no trace, no step, no
+    scope map or no op of the scope."""
+    scopes = facts.get("op_scopes")
+    steps = trace.spans.get("step", 0) if trace is not None else 0
+    if not scopes or not steps:
+        return None
+    t = sum(s for name, s in trace.ops if scopes.get(name) == scope)
+    return 1000.0 * t / steps if t else None
+
+
+def scope_seconds(events: list, scopes: dict, control: set) -> dict:
     """Device seconds inside the window, averaged over devices, of the
-    leaf ops in each scope (``scopes``: op name -> scope, as
-    ``op_scopes`` gives it), under "unscoped" the rest, and under
-    "leaf" all of them."""
+    leaf ops (every op but ``control``, as ``control_ops`` gives it) in
+    each scope (``scopes``: op name -> scope, as ``op_scopes`` gives
+    it), under "unscoped" the rest, and under "leaf" all of them."""
     w0, w1 = _window(events)
     devs = {p for p, l, *_ in events
             if p.startswith("/device:") and l == T.OPS_LINE}
@@ -189,7 +224,7 @@ def scope_seconds(events: list, scopes: dict) -> dict:
     out.update(unscoped=0.0, leaf=0.0)
     for p, l, n, s, d in events:
         if l != T.OPS_LINE or not p.startswith("/device:") \
-                or CONTROL.match(n):
+                or n in control:
             continue
         t = (min(s + d, w1) - max(s, w0)) / 1e9 / len(devs)
         if t > 0:
@@ -211,8 +246,9 @@ def main(argv=None) -> int:
            "idle_by_span": idle_by_span(events)}
     if args.hlo:
         with open(args.hlo) as f:
-            scopes = op_scopes(f.read(), SCOPES)
-        out["scope_s"] = scope_seconds(events, scopes)
+            text = f.read()
+        out["scope_s"] = scope_seconds(events, op_scopes(text, SCOPES),
+                                       control_ops(text))
     print(json.dumps(out, indent=1, sort_keys=True))
     return 0
 

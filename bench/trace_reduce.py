@@ -1,8 +1,8 @@
 """From a profiler trace to the numbers the per-layer metrics read.
 
 ``load(dir)`` flattens the ``.xplane.pb`` that ``jax.profiler.trace``
-wrote into plain events; ``reduce(events)`` works on those alone, so the
-test runs it on a small trace recorded on the chip
+wrote into plain events; ``reduce(events, control)`` works on those
+alone, so the test runs it on a small trace recorded on the chip
 (``tests/data/trace_v5e.json.gz``).
 
 * busy: the union of the intervals in which an XLA op ran on a device,
@@ -10,7 +10,10 @@ test runs it on a small trace recorded on the chip
 * idle gaps: the holes in that union on the first device, each named by
   the innermost benchmark span (``TraceAnnotation``) that covered it;
 * collective exposure: the part of each device's collective ops during
-  which no other op ran there;
+  which no other op ran there.  A ``while``, ``conditional`` or ``call``
+  event spans the ops of its body, a collective among them, so the
+  names in ``control`` (``span_reduce.control_ops`` of the compiled
+  program) do not count as another op;
 * device ops: total device time per op name;
 * module time: device time of the XLA programs whose name holds a given
   string (``jit_<fn>``).
@@ -156,7 +159,7 @@ def _innermost(spans: list, t: int) -> str:
     return best[0] if best else "none"
 
 
-def reduce(events: list) -> Summary:
+def reduce(events: list, control=frozenset()) -> Summary:
     host = [(n, s, s + d) for p, l, n, s, d in events
             if not p.startswith("/device:")]
     windows = [(s, e) for n, s, e in host if n == WINDOW]
@@ -187,7 +190,8 @@ def reduce(events: list) -> Summary:
         coll = clip(union([[s, e] for n, s, e in ops
                            if COLLECTIVE.search(n)]), w0, w1)
         comp = clip(union([[s, e] for n, s, e in ops
-                           if not COLLECTIVE.search(n)]), w0, w1)
+                           if not COLLECTIVE.search(n)
+                           and n not in control]), w0, w1)
         exposed.append(length(subtract(coll, comp)))
         for n, s, e in ops:
             inside = length(clip([[s, e]], w0, w1))
